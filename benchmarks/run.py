@@ -111,6 +111,9 @@ def write_json(out: list[str], mode: str, secs: float) -> str:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="mse,tasks,fl,async,systems,roofline")
     ap.add_argument("--smoke", action="store_true",

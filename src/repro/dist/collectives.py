@@ -67,6 +67,7 @@ bytes exceed the decoded vector's d bytes (asserted in tests + benchmarks).
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Any
 
 import jax
@@ -116,10 +117,12 @@ def _chunk_clients(tree, d_block: int):
     """
     n = jax.tree.leaves(tree)[0].shape[0]
     _, restore = chunking.tree_chunk(_client_slice(tree, 0), d_block)
-    chunks = jax.vmap(
-        lambda i: chunking.tree_chunk(_client_slice(tree, i), d_block)[0]
-    )(jnp.arange(n))
-    return chunks, restore, n
+    return _vmap_chunk(tree, d_block), restore, n
+
+
+def _vmap_chunk(tree, d_block: int):
+    """(n, ...) leaves -> (n, C, d_block) chunks, one client per row."""
+    return jax.vmap(lambda t: chunking.tree_chunk(t, d_block)[0])(tree)
 
 
 def _info(pipe, n: int, d_flat: int, n_chunks: int, n_total: int | None = None,
@@ -502,7 +505,7 @@ def compressed_mean_tree_shardmap(spec, key, grads, mesh, param_pspecs=None,
     are all-gathered across the client axes (the only payload-sized cross-
     client traffic), and every shard runs the identical server decode.
     Requires n_clients divisible by the client-axes extent; falls back to the
-    GSPMD path otherwise.
+    GSPMD path otherwise, with a ``RuntimeWarning`` that says so.
 
     Error feedback (ErrorFeedback stage): ``ef_chunks`` (n, C, d_block) is
     sharded over the client axis, so each residual row lives with its
@@ -530,8 +533,6 @@ def compressed_mean_tree_shardmap(spec, key, grads, mesh, param_pspecs=None,
     (self-decode runs on the client's own shard from its pre-routing
     payloads).
     """
-    from jax.experimental.shard_map import shard_map
-
     pipe = as_pipeline(spec)
     client_axes = tuple(a for a in client_axes if a in mesh.axis_names)
     n = jax.tree.leaves(grads)[0].shape[0]
@@ -539,6 +540,10 @@ def compressed_mean_tree_shardmap(spec, key, grads, mesh, param_pspecs=None,
     for a in client_axes:
         n_shards *= mesh.shape[a]
     if not client_axes or n % n_shards != 0:
+        warnings.warn(
+            f"compressed_mean_tree_shardmap: {n} clients do not divide over "
+            f"mesh client axes {client_axes} ({n_shards} shards); running the "
+            "GSPMD compressed_mean_tree instead", RuntimeWarning, stacklevel=2)
         return compressed_mean_tree(
             pipe, key, grads, dme_shardings(mesh, client_axes),
             ef_chunks=ef_chunks, participants=participants,
@@ -578,9 +583,7 @@ def compressed_mean_tree_shardmap(spec, key, grads, mesh, param_pspecs=None,
         for a in client_axes:
             shard_idx = shard_idx * mesh.shape[a] + jax.lax.axis_index(a)
         ids = shard_idx * n_local + jnp.arange(n_local)
-        chunks = jax.vmap(
-            lambda i: chunking.tree_chunk(_client_slice(g_local, i), pipe.d_block)[0]
-        )(jnp.arange(n_local))
+        chunks = _vmap_chunk(g_local, pipe.d_block)
         x = chunks + ef_local if use_ef else chunks
 
         def encode_local(x_cols):
@@ -730,9 +733,9 @@ def compressed_mean_tree_shardmap(spec, key, grads, mesh, param_pspecs=None,
     # all_gather/all_to_all + decode run fused in the traced program)
     with obs.span("dist", "payload_route", track="payload_route",
                   backend="shard_map", shards=n_shards):
-        mean_tree, ef_next = shard_map(
-            local_fn, mesh, in_specs=in_specs,
-            out_specs=(mean_specs, client_spec), check_rep=False,
+        mean_tree, ef_next = jax.shard_map(
+            local_fn, mesh=mesh, in_specs=in_specs,
+            out_specs=(mean_specs, client_spec), check_vma=False,
         )(key, grads, ef_chunks)
     if not use_ef:
         ef_next = None
@@ -762,8 +765,6 @@ def psum_scatter_mean(tiles, counts, mesh, axis: str = "pod"):
     CPU-backend equivalent of this combine (multiprocess XLA collectives are
     unavailable there); on TPU/GPU meshes this is the fast path.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     tiles = jnp.asarray(tiles)
     counts = jnp.asarray(counts, tiles.dtype)
@@ -787,8 +788,8 @@ def psum_scatter_mean(tiles, counts, mesh, axis: str = "pod"):
         full = jax.lax.all_gather(part / total, axis, axis=0, tiled=True)
         return full[:n_chunks]
 
-    return shard_map(
-        local_fn, mesh,
+    return jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis)),
-        out_specs=P(None, None), check_rep=False,
+        out_specs=P(None, None), check_vma=False,
     )(tiles, counts)

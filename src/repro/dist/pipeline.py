@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -74,6 +73,7 @@ def pipeline_apply(stage_fn, staged, x, mesh, axis: str = "pipe"):
         P(*([None] * x.ndim)),
     )
     out_specs = P(*([None] * x.ndim))
-    return shard_map(
-        shard_fn, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )(staged, x)

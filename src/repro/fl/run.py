@@ -134,9 +134,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "estimates; 1 is the flat path (bitwise identical)")
     ap.add_argument("--hosts", type=int, default=1,
                     help=">= 2 forks that many CPU processes via "
-                         "runtime.spawn_local, each decoding its owned pods "
-                         "(or joins an existing runtime when REPRO_PROCESS_ID "
-                         "is set by a cluster launcher)")
+                         "runtime.spawn_local (needs JAX_PLATFORMS=cpu), each "
+                         "decoding its owned pods (or joins an existing "
+                         "runtime when REPRO_PROCESS_ID is set by a cluster "
+                         "launcher)")
     ap.add_argument("--coordinator", default=None, metavar="HOST:PORT",
                     help="jax.distributed coordinator address for --hosts "
                          ">= 2 under an external launcher (default: "
@@ -213,7 +214,9 @@ def run_one(task, args, name, est_kw, ctx=None):
         # all local devices become the client axis (1 device on plain CPU)
         import jax
 
-        mesh = jax.make_mesh((jax.device_count(),), ("pod",))
+        from ..launch.mesh import make_mesh
+
+        mesh = make_mesh((jax.device_count(),), ("pod",))
     cfg = rounds_lib.RoundConfig(
         n_rounds=3 if args.smoke else args.rounds, seed=args.seed,
         temporal=args.temporal, backend=args.backend, mesh=mesh,
@@ -345,6 +348,9 @@ def main(argv=None) -> int:
     import os
     import sys
 
+    from ..launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
 
     from ..runtime import launch as launch_lib

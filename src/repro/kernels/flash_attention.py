@@ -84,7 +84,7 @@ def flash_attention_pallas(
     q_offset: int = 0,
     q_tile: int = 128,
     kv_tile: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     nq, sq, dh = q.shape
     _, sk, _ = k.shape

@@ -26,7 +26,8 @@ Rademacher sign flip (D_i) and the 1/sqrt(d) scale are fused into the kernel
 encode is a single VMEM-resident pass over the data.
 
 Validated against the pure-jnp oracle (kernels/ref.py) in interpret mode on
-CPU; on TPU the same kernel lowers via Mosaic.
+CPU; on TPU the same kernel lowers via Mosaic. ``interpret`` defaults to
+False: a caller that wants the interpreter says so.
 """
 from __future__ import annotations
 
@@ -39,30 +40,41 @@ from jax.experimental import pallas as pl
 from . import ref as _ref
 
 
-def _kernel(h_a_ref, h_b_ref, s_ref, x_ref, o_ref, *, a: int, b: int, with_signs: bool):
-    x = x_ref[...].astype(jnp.float32)  # (bt, d)
+# Mosaic rounds float32 matmul operands to bfloat16 by default: on a v5e the
+# transform then misses the oracle by a relative 3e-3, enough to stall the
+# decode's CG solve. HIGHEST keeps the products exact in float32.
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _fwht_tile(x, h_a_ref, h_b_ref, *, a: int, b: int):
+    """H_a (x) H_b applied to each row of a (bt, d) tile, d = a*b."""
     bt = x.shape[0]
-    if with_signs:
-        x = x * s_ref[...].astype(jnp.float32)  # (1, d) broadcast over rows
     # stage 1: mix within contiguous groups of b (lane dimension).
     xg = x.reshape(bt * a, b)
     y = jax.lax.dot_general(
         xg, h_b_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=_PRECISION,
         preferred_element_type=jnp.float32,
     )  # (bt*a, b); H_b symmetric so X @ H_b == X @ H_b^T
-    if a > 1:
-        # stage 2: mix across the a groups (sublane dimension).
-        y3 = y.reshape(bt, a, b)
-        z = jax.lax.dot_general(
-            h_a_ref[...], y3,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (a, bt, b)
-        out = z.transpose(1, 0, 2).reshape(bt, a * b)
-    else:
-        out = y.reshape(bt, b)
-    o_ref[...] = out.astype(o_ref.dtype)
+    if a == 1:
+        return y.reshape(bt, b)
+    # stage 2: mix across the a groups (sublane dimension).
+    y3 = y.reshape(bt, a, b)
+    z = jax.lax.dot_general(
+        h_a_ref[...], y3,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_PRECISION,
+        preferred_element_type=jnp.float32,
+    )  # (a, bt, b)
+    return z.transpose(1, 0, 2).reshape(bt, a * b)
+
+
+def _kernel(h_a_ref, h_b_ref, s_ref, x_ref, o_ref, *, a: int, b: int, with_signs: bool):
+    x = x_ref[...].astype(jnp.float32)  # (bt, d)
+    if with_signs:
+        x = x * s_ref[...].astype(jnp.float32)  # (1, d) broadcast over rows
+    o_ref[...] = _fwht_tile(x, h_a_ref, h_b_ref, a=a, b=b).astype(o_ref.dtype)
 
 
 def _split_dims(d: int) -> tuple[int, int]:
@@ -72,10 +84,23 @@ def _split_dims(d: int) -> tuple[int, int]:
     return d // b, b
 
 
-def _pick_block_rows(n_rows: int, d: int) -> int:
-    # keep in/out tiles + constants well under ~8 MiB of VMEM.
-    budget = 2 * 1024 * 1024  # floats per tile buffer
-    bt = max(8, budget // d)
+# Bytes of VMEM the blocked (bt, d) operands may take, double-buffered: half
+# of a TPU v5e core's 16 MiB scoped VMEM limit. The other half holds the
+# kernel body's own (bt, d) temporaries (the two Kronecker stages and, at
+# HIGHEST precision, the bfloat16 splits of their operands) and the Hadamard
+# constants. At 12 MiB the three-operand kernels overran the limit by 48 KiB.
+_VMEM_TILE_BUDGET = 8 * 1024 * 1024
+
+
+def _pick_block_rows(n_rows: int, d: int, n_tiles: int = 2) -> int:
+    """Tile height bt for a kernel with ``n_tiles`` blocked (bt, d) operands
+    (inputs and output). Pallas double-buffers every blocked operand, so the
+    kernel holds ``2 * n_tiles`` tiles of bt*d float32 at once; bt is the
+    largest power of two that keeps them inside ``_VMEM_TILE_BUDGET``,
+    floored at 8 rows and capped at max(8, n_rows) (a block as tall as the
+    whole, padded array is always legal)."""
+    per_row = 2 * n_tiles * d * 4
+    bt = max(8, _VMEM_TILE_BUDGET // per_row)
     bt = 1 << (bt.bit_length() - 1)  # round down to power of two
     return int(min(bt, max(8, n_rows)))
 
@@ -90,7 +115,7 @@ def fwht_pallas(
     with_signs: bool = False,
     scale: float = 1.0,
     block_rows: int | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Batched FWHT over the last axis: ``scale * H_d @ (signs? * x)``.
 

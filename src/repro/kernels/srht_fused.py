@@ -28,10 +28,14 @@ applied as an explicit elementwise multiply after the transform — exactly
 where `kernels/ref.py` applies it — so interpret mode is bit-exact against
 the oracle composition (see the golden tests in tests/test_kernels.py).
 
-VMEM budget: a (block_chunks, d) tile per operand plus the (a, a), (b, b)
-Hadamard constants; `_pick_block_rows` keeps each buffer under 2M floats
-(~8 MiB), identical to the fwht.py policy. See docs/KERNELS.md for the
-worked walkthrough.
+VMEM budget: Pallas double-buffers every blocked operand, so a kernel with
+m (block_chunks, d) operands (per-chunk inputs plus the output) holds 2m
+such tiles, plus the (a, a), (b, b) Hadamard constants and the kernel
+body's own temporaries. Each wrapper tells `fwht._pick_block_rows` its m,
+which sizes the tiles so the 2m of them stay inside 8 MiB, half of the v5e's
+16 MiB scoped VMEM limit (at d = 1024: 512 rows for m = 2, 256 for m = 3 or
+4). Shared (1, d) sign or mask rows are not counted. See docs/KERNELS.md for
+the worked walkthrough.
 """
 from __future__ import annotations
 
@@ -42,28 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import ref as _ref
-from .fwht import _pick_block_rows, _split_dims
-
-
-def _fwht_tile(x, h_a_ref, h_b_ref, *, a: int, b: int):
-    """Unnormalised H_d @ x for a (bt, d) tile via the two-matmul Kronecker
-    factorisation (same dataflow as fwht._kernel)."""
-    bt = x.shape[0]
-    xg = x.reshape(bt * a, b)
-    y = jax.lax.dot_general(
-        xg, h_b_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    if a > 1:
-        y3 = y.reshape(bt, a, b)
-        z = jax.lax.dot_general(
-            h_a_ref[...], y3,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return z.transpose(1, 0, 2).reshape(bt, a * b)
-    return y.reshape(bt, b)
+from .fwht import _fwht_tile, _pick_block_rows, _split_dims
 
 
 def _rowsigns_kernel(
@@ -152,7 +135,7 @@ def fwht_rowsigns_pallas(
     sign_post: bool = False,
     scale: float = 1.0,
     block_rows: int | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused batched FWHT with per-row Rademacher diagonals.
 
@@ -162,7 +145,7 @@ def fwht_rowsigns_pallas(
     """
     rows, d = x.shape
     a, b = _split_dims(d)
-    bt = block_rows or _pick_block_rows(rows, d)
+    bt = block_rows or _pick_block_rows(rows, d, n_tiles=3)
     x = _pad_chunk_axis(x, 0, bt)
     signs = _pad_chunk_axis(signs.astype(x.dtype), 0, bt)
     n_tiles = x.shape[0] // bt
@@ -194,7 +177,7 @@ def srht_decode_sum_pallas(
     *,
     scale: float,
     block_rows: int | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused inverse-SRHT + sign/scale + scatter-add over clients.
 
@@ -207,8 +190,7 @@ def srht_decode_sum_pallas(
     n, c, d = u.shape
     shared = signs.shape[1] == 1
     a, b = _split_dims(d)
-    bt = block_rows or _pick_block_rows(c, d)
-    bt = min(bt, max(8, c))
+    bt = block_rows or _pick_block_rows(c, d, n_tiles=2 if shared else 3)
     u = _pad_chunk_axis(u, 1, bt)
     if not shared:
         signs = _pad_chunk_axis(signs, 1, bt)
@@ -244,7 +226,7 @@ def srht_gram_apply_pallas(
     *,
     scale: float,
     block_rows: int | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused matrix-free ``S v = sum_i G_i^T G_i v`` for SRHT maps.
 
@@ -257,8 +239,8 @@ def srht_gram_apply_pallas(
     c, d = v.shape
     n = signs.shape[0]
     a, b = _split_dims(d)
-    bt = block_rows or _pick_block_rows(c, d)
-    bt = min(bt, max(8, c))
+    n_tiles = 2 + (signs.shape[1] != 1) + (mask.shape[1] != 1)
+    bt = block_rows or _pick_block_rows(c, d, n_tiles=n_tiles)
     v = _pad_chunk_axis(v, 0, bt)
     if signs.shape[1] != 1:
         signs = _pad_chunk_axis(signs, 1, bt)

@@ -31,7 +31,7 @@ from ..optim import AdamW
 from ..train import make_train_step
 from ..core import codec
 from . import hlo_stats, specs
-from .mesh import make_production_mesh
+from .mesh import make_mesh, make_production_mesh
 
 RESULT_DIR_DEFAULT = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results", "dryrun")
 
@@ -120,7 +120,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, dme: str, knobs=None) 
     try:
         if "mesh_shape" in knobs:
             # ablation meshes, e.g. [2, 256, 1] = DP-dominant 2-pod (§Perf H-c.4)
-            mesh = jax.make_mesh(tuple(knobs["mesh_shape"]), ("pod", "data", "model"))
+            mesh = make_mesh(tuple(knobs["mesh_shape"]), ("pod", "data", "model"))
             rec["mesh"] = "x".join(str(s) for s in knobs["mesh_shape"])
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)

@@ -9,11 +9,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """A mesh whose axes are all ``Auto``. The train step and the FL rounds
+    place work with sharding constraints and leave the rest to GSPMD; the
+    ``Explicit`` axes ``jax.make_mesh`` gives by default refuse both (the
+    constraints, and the per-client ``vmap`` over a client-sharded batch)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants for the roofline model (docs/EXPERIMENTS.md §Roofline)

@@ -10,6 +10,8 @@
   the axis shards over 'pod').
 - Fault tolerance: checkpoints every --ckpt-every steps; restart the same
   command line and it resumes; --inject-failures demonstrates recovery.
+  --max-restarts 0 makes the first step failure fatal (a compile error then
+  surfaces once instead of recompiling on every retry).
 """
 from __future__ import annotations
 
@@ -27,6 +29,8 @@ from ..optim import AdamW
 from ..train import make_train_step
 from ..train.train_step import init_train_state
 from ..train.supervisor import FaultPlan, Supervisor
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def preset_config(arch: str, preset: str):
@@ -49,7 +53,10 @@ def preset_config(arch: str, preset: str):
     return cfg.replace(**kw)
 
 
-def main(argv=None):
+def main(argv=None, log_fn=print):
+    """Run the CLI; ``log_fn`` receives every step and Supervisor line.
+    Returns the Supervisor's [(step, loss), ...] history."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m", choices=list(configs.ARCHS))
     ap.add_argument("--preset", default="tiny", choices=["tiny", "small", "full"])
@@ -75,13 +82,18 @@ def main(argv=None):
     ap.add_argument("--non-iid", type=float, default=0.0)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="log (and record in the history) every Nth step's loss")
+    ap.add_argument("--max-restarts", type=int, default=10,
+                    help="step failures the Supervisor restores from before "
+                         "it re-raises")
     ap.add_argument("--inject-failures", default="", help="comma steps, e.g. 30,80")
     ap.add_argument("--resize", default="", help="step:new_n, e.g. 100:3")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     cfg = preset_config(args.arch, args.preset)
-    print(f"[train] {cfg.name} preset={args.preset}: {cfg.n_params()/1e6:.1f}M params, "
+    log_fn(f"[train] {cfg.name} preset={args.preset}: {cfg.n_params()/1e6:.1f}M params, "
           f"{args.clients or 1} clients, estimator="
           f"{args.estimator if args.clients else 'none (uncompressed)'}")
     optimizer = AdamW(lr=args.lr, warmup_steps=20)
@@ -93,7 +105,7 @@ def main(argv=None):
 
     pipe_mesh = None
     if args.pipeline_stages:
-        pipe_mesh = jax.make_mesh((args.pipeline_stages,), ("pipe",))
+        pipe_mesh = make_mesh((args.pipeline_stages,), ("pipe",))
 
     def make_step(n_clients):
         spec = dme
@@ -128,11 +140,13 @@ def main(argv=None):
         make_step=make_step, make_data=make_data, init_state=init_state,
         ckpt_dir=os.path.join(args.ckpt_dir, f"{cfg.name}_{args.preset}"),
         n_clients=args.clients, ckpt_every=args.ckpt_every,
+        max_restarts=args.max_restarts,
     )
-    params, state, history = sup.run(args.steps, fault_plan=plan)
+    params, state, history = sup.run(args.steps, fault_plan=plan,
+                                     log_every=args.log_every, log_fn=log_fn)
     if history:
         first, last = history[0][1], history[-1][1]
-        print(f"[train] loss {first:.4f} -> {last:.4f} over {args.steps} steps")
+        log_fn(f"[train] loss {first:.4f} -> {last:.4f} over {args.steps} steps")
     return history
 
 

@@ -71,11 +71,15 @@ def ssd_chunked(x, dt, a_log, bb, cc, dd, chunk: int, unroll=1):
     xdt = xr * dtr[..., None]
 
     # intra-chunk: y_q += C_q . sum_{k<=q} exp(cs_q - cs_k) dt_k B_k x_k
-    # decay: (b, nc, h, q, k); all exponents <= 0 (stable).
+    # decay: (b, nc, h, q, k). The causal mask goes on the exponent, not on
+    # exp(): above the diagonal the exponent is positive, exp overflows at a
+    # full 256-step chunk, and masking inf after the fact makes the backward
+    # pass NaN (0 * inf). Kept entries have exponents <= 0 (stable).
     csh = cs.transpose(0, 1, 3, 2)
-    decay = jnp.exp(csh[:, :, :, :, None] - csh[:, :, :, None, :])
     mask = jnp.tril(jnp.ones((q, q), bool))
-    decay = jnp.where(mask[None, None, None], decay, 0.0)
+    decay = jnp.exp(jnp.where(mask[None, None, None],
+                              csh[:, :, :, :, None] - csh[:, :, :, None, :],
+                              -jnp.inf))
     scores = jnp.einsum("bcqhn,bckhn->bchqk", cr, br) * decay
     y_intra = jnp.einsum("bchqk,bckhp->bcqhp", scores, xdt)
 

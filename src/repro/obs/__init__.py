@@ -23,6 +23,7 @@ Three submodules:
   compile-time telemetry hooks.
 """
 from .profile import (  # noqa: F401
+    main_path_faults,
     profiler_session,
     record_cg_iters,
     record_compile,

@@ -18,6 +18,8 @@ Two kinds of hook live here, both no-ops unless explicitly requested:
   (once per trace — i.e. per compilation); CG iterations are data-dependent
   and therefore recorded only on eager executions (under jit the sample is
   a tracer and the registry drops it — the tracer-safety contract).
+  ``main_path_faults`` reads those counters back (plus the Supervisor's log)
+  and names every way a run left the compiled kernel path.
 """
 from __future__ import annotations
 
@@ -55,6 +57,45 @@ def record_dispatch(op: str, use_kernel: bool, interpret: bool) -> None:
     route = ("pallas_interpret" if use_kernel and interpret
              else "pallas" if use_kernel else "oracle")
     registry.count("kernels", "dispatch", op=op, route=route)
+
+
+_KERNEL_OPS = ("fwht", "srht_")
+
+
+def main_path_faults(counters: dict, log_lines=()) -> list[str]:
+    """Why a run did not take the compiled main path; empty when it did.
+
+    ``counters`` is ``snapshot()["counters"]`` of a run with the registry
+    enabled; ``log_lines`` are the lines a ``train.Supervisor`` logged. The
+    run is refused when:
+
+    - no ``fwht``/``srht_*`` dispatch was recorded, or one took a route
+      other than ``pallas`` (the interpreter, or the jnp oracle);
+    - no ``rand_proj_spatial`` decode was recorded, or one was not ``fused``;
+    - the Supervisor restarted a step or resumed from a checkpoint.
+
+    A Supervisor with ``max_restarts=0`` re-raises its first step failure
+    instead of logging a restart, so for such a run only the ``resumed``
+    line can show here; the ``failed`` line is what a run that retries logs.
+    """
+    labels = registry.labels_of
+    faults = []
+    dispatch = [k for k in counters if k.startswith("kernels/dispatch{")
+                and labels(k).get("op", "").startswith(_KERNEL_OPS)]
+    if not dispatch:
+        faults.append("no fwht/srht_* kernel dispatch was recorded")
+    faults += [f"{k} took route {labels(k)['route']!r}, not 'pallas'"
+               for k in dispatch if labels(k)["route"] != "pallas"]
+    routes = [k for k in counters if k.startswith("kernels/decode_route{")
+              and labels(k).get("estimator") == "rand_proj_spatial"]
+    if not routes:
+        faults.append("no rand_proj_spatial decode was recorded")
+    faults += [f"{k}: decode was not 'fused'"
+               for k in routes if labels(k)["method"] != "fused"]
+    faults += [f"supervisor: {line}" for line in log_lines
+               if line.startswith("[supervisor]")
+               and ("failed" in line or "resumed" in line)]
+    return faults
 
 
 def record_decode_route(estimator: str, method: str) -> None:

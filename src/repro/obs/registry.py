@@ -100,6 +100,14 @@ def _key(component: str, name: str, labels: dict) -> str:
     return f"{base}{{{inner}}}"
 
 
+def labels_of(key: str) -> dict:
+    """The labels of a counter key: the inverse of ``_key``'s ``{k=v,...}``."""
+    if "{" not in key:
+        return {}
+    inner = key[key.index("{") + 1: -1]
+    return dict(kv.split("=", 1) for kv in inner.split(","))
+
+
 def count(component: str, name: str, value: float = 1, **labels) -> None:
     """Add ``value`` to a counter (keyed by component/name + sorted labels)."""
     if not _STATE.enabled:

@@ -20,7 +20,10 @@ Three pieces (docs/DESIGN.md §11.1):
 - ``spawn_local(worker, n)`` — forks ``n`` fresh CPU processes (spawn
   context: children re-import, so env set here governs their jax), wires
   them to a coordinator on a free localhost port, runs
-  ``worker(ctx, *args)`` in each, and returns the per-process results.
+  ``worker(ctx, *args)`` in each, and returns the per-process results. It is
+  a CPU test harness: it refuses to run unless the caller asked for the CPU
+  (``JAX_PLATFORMS=cpu``), so on an accelerator host it fails loudly instead
+  of running the rounds on the host CPU.
 
 Coordinator discovery order: explicit argument > ``REPRO_COORDINATOR`` >
 single-process (no coordinator needed).
@@ -230,10 +233,17 @@ def spawn_local(worker, n_processes: int, *, args: tuple = (),
     context starts clean interpreters, which is exactly what lets each child
     own its jax runtime (the parent's backend state never leaks in).
     Children talk to a coordinator hosted by child 0 on a free localhost
-    port. Raises RuntimeError carrying the child tracebacks on any failure.
+    port. Raises RuntimeError carrying the child tracebacks on any failure,
+    and before forking anything when ``JAX_PLATFORMS`` is not ``cpu``.
     """
     if n_processes < 1:
         raise ValueError(f"n_processes must be >= 1, got {n_processes}")
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "spawn_local forks CPU-only worker processes; set "
+            "JAX_PLATFORMS=cpu to ask for that, or start one process per "
+            "host under a cluster launcher (REPRO_PROCESS_ID et al.)"
+        )
     coordinator = f"127.0.0.1:{free_port()}"
     mp = multiprocessing.get_context("spawn")
     procs, conns = [], []

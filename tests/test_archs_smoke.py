@@ -134,3 +134,26 @@ def test_full_config_param_counts():
         assert lo <= n <= hi, f"{name}: {n:.2f}B params out of [{lo},{hi}]"
         if cfg.n_experts:
             assert cfg.n_params_active() < cfg.n_params()
+
+
+def test_ssd_grads_finite_over_a_full_chunk():
+    """mamba2's 256-step SSD chunk accumulates more than exp's float32 range
+    of decay; the masked (acausal) half of the decay matrix must not turn
+    that overflow into NaN gradients."""
+    from repro.models.mamba import ssd_chunked
+
+    b, l, h, p, n, chunk = 1, 256, 2, 4, 8, 256
+    ks = jax.random.split(jax.random.key(3), 4)
+    x = jax.random.normal(ks[0], (b, l, h, p))
+    bb = jax.random.normal(ks[1], (b, l, h, n))
+    cc = jax.random.normal(ks[2], (b, l, h, n))
+    dt = jnp.full((b, l, h), 0.1)
+    a_log = jnp.log(jnp.full((h,), 8.0))  # 256 * 8 * 0.1 = 205 >> log(f32 max)
+
+    def loss(x, a_log):
+        y, _ = ssd_chunked(x, dt, a_log, bb, cc, jnp.ones((h,)), chunk)
+        return jnp.sum(y ** 2)
+
+    val, grads = jax.value_and_grad(loss, argnums=(0, 1))(x, a_log)
+    assert bool(jnp.isfinite(val))
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)

@@ -367,3 +367,18 @@ def test_ef_stage_residual_matches_collectives_buffer():
     _, _, ef = collectives.compressed_mean_tree(pipe, key, {"x": xs[:, 0, :]})
     np.testing.assert_allclose(np.asarray(st2.ef), np.asarray(ef),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_shardmap_exchange_warns_when_it_falls_back():
+    """A mesh without the client axis cannot host the shard_map exchange:
+    the GSPMD path runs instead (same numbers), and a warning says so."""
+    from repro.dist import collectives
+
+    mesh = jax.make_mesh((1,), ("data",))
+    pipe = codec.RandProjSpatial(k=8, d_block=D, transform="avg")
+    grads = {"w": jax.random.normal(jax.random.key(0), (3, 2 * D))}
+    with pytest.warns(RuntimeWarning, match="GSPMD"):
+        mean, _, _ = collectives.compressed_mean_tree_shardmap(
+            pipe, jax.random.key(1), grads, mesh)
+    want, _, _ = collectives.compressed_mean_tree(pipe, jax.random.key(1), grads)
+    np.testing.assert_array_equal(np.asarray(mean["w"]), np.asarray(want["w"]))

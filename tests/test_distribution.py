@@ -17,6 +17,7 @@ from repro.core import codec
 from repro.data import SyntheticLM
 from repro.dist import collectives
 from repro.dist.sharding import MODEL_PREF, spec_for
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.optim import AdamW
 from repro.train import make_train_step
@@ -43,6 +44,15 @@ def test_spec_for_divisibility():
     # pod axis never assigned to params
     mesh3 = FakeMesh((2, 16, 16), ("pod", "data", "model"))
     assert spec_for((5120, 5120), ("embed", "heads"), mesh3) == P("data", "model")
+
+
+@pytest.mark.parametrize("axes", [("pod",), ("pod", "data", "model")])
+def test_make_mesh_axes_are_auto(axes):
+    """The package's meshes take Auto axes, not jax.make_mesh's Explicit
+    default: the GSPMD exchange's sharding constraints refuse Explicit."""
+    mesh = make_mesh((1,) * len(axes), axes)
+    assert mesh.axis_names == axes
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def test_compressed_mean_identity_is_exact():
@@ -126,7 +136,8 @@ _SUBPROC = textwrap.dedent(
     from repro.train import make_train_step
     from repro.core import codec
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = configs.reduce_for_smoke(configs.get_config("{arch}")).replace(
         vocab_pad_multiple=32)
     opt = AdamW()

@@ -74,6 +74,21 @@ def test_supervisor_recovers_from_injected_failures(tmp_path):
     assert ckpt.latest_step(str(tmp_path / "ck")) == 15
 
 
+def test_supervisor_restart_fails_the_main_path_check(tmp_path):
+    """A run that only finished through a Supervisor restart is refused by
+    the chip smoke's check, even though it reached its last step."""
+    from repro import obs
+
+    lines = []
+    sup = _mk_supervisor(tmp_path / "ck")
+    sup.run(4, fault_plan=FaultPlan(fail_at_steps=(2,)), log_every=1,
+            log_fn=lines.append)
+    assert any(line.startswith("[step 3]") for line in lines), lines
+    faults = obs.main_path_faults({}, lines)
+    restart = [f for f in faults if f.startswith("supervisor:")]
+    assert len(restart) == 1 and "step 2 failed" in restart[0], faults
+
+
 def test_supervisor_resume_matches_uninterrupted(tmp_path):
     """Crash-restore must reproduce the uninterrupted trajectory exactly
     (pure-function-of-step data + checkpointed state)."""

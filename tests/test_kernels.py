@@ -9,7 +9,12 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.fwht import _pick_block_rows, _split_dims, fwht_pallas
+from repro.kernels.fwht import (
+    _VMEM_TILE_BUDGET,
+    _pick_block_rows,
+    _split_dims,
+    fwht_pallas,
+)
 
 
 @pytest.mark.parametrize("d", [2, 8, 64, 128, 256, 1024, 2048])
@@ -133,18 +138,39 @@ def test_fwht_pallas_ragged_rows_pad_and_unpad(rows):
 
 def test_pick_block_rows_bounds():
     """The autotuned tile height stays a power of two, >= 8, and within the
-    VMEM budget — the contract _pick_block_rows documents."""
-    for n_rows, d in [(1, 128), (7, 512), (1000, 4096), (64, 1 << 16)]:
-        bt = _pick_block_rows(n_rows, d)
-        assert bt >= 8
-        assert bt & (bt - 1) == 0
-        assert bt * d <= 2 * 1024 * 1024 or bt == 8
+    VMEM budget — the contract _pick_block_rows documents: every blocked
+    operand's (bt, d) float32 tile, double-buffered, fits the budget."""
+    for n_rows, d in [(1, 128), (7, 512), (1000, 4096), (64, 1 << 16),
+                      (126_075, 1024)]:
+        for n_tiles in (2, 3, 4):
+            bt = _pick_block_rows(n_rows, d, n_tiles=n_tiles)
+            assert bt >= 8
+            assert bt & (bt - 1) == 0
+            assert 2 * n_tiles * bt * d * 4 <= _VMEM_TILE_BUDGET or bt == 8
+    # a v5e core's scoped VMEM limit is 16 MiB
+    assert _VMEM_TILE_BUDGET < 16 * 1024 * 1024
     # and fwht_pallas accepts the default pick end-to-end on a ragged batch
     x = jnp.asarray(np.random.default_rng(0).standard_normal((7, 512)),
                     jnp.float32)
     np.testing.assert_allclose(
         np.asarray(fwht_pallas(x, interpret=True)),
         np.asarray(ref.fwht_ref(x)), atol=1e-3)
+
+
+@pytest.mark.parametrize("name", [
+    "fwht.fwht_pallas", "srht_fused.fwht_rowsigns_pallas",
+    "srht_fused.srht_decode_sum_pallas", "srht_fused.srht_gram_apply_pallas",
+    "flash_attention.flash_attention_pallas",
+])
+def test_kernel_wrappers_default_to_compiled(name):
+    """A caller that leaves ``interpret`` out gets the compiled kernel; the
+    interpreter is only ever asked for by name (tests, ops' CPU route)."""
+    import importlib
+    import inspect
+
+    module, fn = name.split(".")
+    wrapper = getattr(importlib.import_module(f"repro.kernels.{module}"), fn)
+    assert inspect.signature(wrapper).parameters["interpret"].default is False
 
 
 def test_fwht_involution_and_parseval_seeded():

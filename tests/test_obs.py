@@ -16,6 +16,7 @@ import pytest
 from repro import obs
 from repro.core import codec
 from repro.fl import Cohort, RoundConfig, get_task, run_rounds
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -132,7 +133,7 @@ def test_disabled_run_bitwise_identical(backend):
     running fully disabled produce byte-for-byte identical History metrics —
     instrumentation never perturbs the math."""
     kw = {} if backend == "local" else dict(
-        mesh=jax.make_mesh((jax.device_count(),), ("pod",)))
+        mesh=make_mesh((jax.device_count(),), ("pod",)))
 
     _, h_off = _run(backend=backend, **kw)
 
@@ -278,3 +279,44 @@ def test_compare_metrics_json_is_per_run(tmp_path, capsys):
                    if "client_encode" in k]
         # each run's snapshot counts ITS 3 rounds, not a running total
         assert encodes and sum(encodes) == 3.0, entry["metrics"]["counters"]
+
+
+# ---------------------------------------------- main-path check (chip smoke)
+
+
+def _main_path_counters():
+    return {
+        "kernels/dispatch{op=srht_encode_batch,route=pallas}": 1.0,
+        "kernels/dispatch{op=srht_decode_sum,route=pallas}": 1.0,
+        "kernels/decode_route{estimator=rand_proj_spatial,method=fused}": 1.0,
+    }
+
+
+def test_main_path_faults_accepts_the_compiled_path():
+    lines = ["[step 0] loss=10.9 (80.1s)", "[step 1] loss=10.8 (0.9s)"]
+    assert obs.main_path_faults(_main_path_counters(), lines) == []
+
+
+def test_main_path_faults_refuses_interpret_mode():
+    """A decode forced onto the Pallas interpreter (what a CPU host runs)
+    is refused by name, as are runs that recorded no kernel or decode."""
+    obs.enable()
+    pipe = codec.Pipeline([codec.RandProjSpatial(
+        k=8, d_block=D, transform="avg", use_pallas="force")])
+    xs = jnp.asarray(np.random.default_rng(0).standard_normal((3, 2, D)),
+                     jnp.float32)
+    payloads, _ = pipe.encode_all(jax.random.key(0), xs)
+    pipe.decode(jax.random.key(0), payloads, 3)
+    faults = obs.main_path_faults(obs.snapshot()["counters"])
+    assert faults and all("pallas_interpret" in f for f in faults), faults
+    assert any("op=srht_decode_sum" in f for f in faults), faults
+    assert len(obs.main_path_faults({})) == 2
+
+
+def test_main_path_faults_refuses_unfused_decode_and_restarts():
+    counters = dict(_main_path_counters())
+    counters["kernels/decode_route{estimator=rand_proj_spatial,method=gram}"] = 1.0
+    lines = ["[supervisor] resumed from step 19", "[step 0] loss=1.0 (1s)"]
+    faults = obs.main_path_faults(counters, lines)
+    assert len(faults) == 2, faults
+    assert "method=gram" in faults[0] and "resumed" in faults[1]

@@ -37,6 +37,7 @@ from repro.core import codec
 from repro.dist import collectives
 from repro.dist.sharding import ChunkOwnership, chunk_ownership
 from repro.fl import Cohort, RoundConfig, get_task, run_rounds
+from repro.launch.mesh import make_mesh
 
 D = 128
 K = 16
@@ -330,7 +331,7 @@ def test_rounds_ownership_parity(backend):
     task = get_task("drift", n_clients=8, d=D, rho=0.95, omega=0.02)
     pipe = codec.RandProjSpatial(k=K, d_block=D, transform="avg")
     cohort = Cohort(n_clients=8, dropout=0.2)
-    mesh = None if backend == "local" else jax.make_mesh(
+    mesh = None if backend == "local" else make_mesh(
         (jax.device_count(),), ("pod",))
     base = dict(n_rounds=4, backend=backend, mesh=mesh)
     _, h0 = run_rounds(task, pipe, cohort, RoundConfig(**base))

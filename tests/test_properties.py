@@ -37,6 +37,7 @@ import pytest
 from repro.core import codec
 from repro.dist import collectives
 from repro.dist.sharding import chunk_ownership
+from repro.launch.mesh import make_mesh
 
 D = 64
 C = 2
@@ -256,7 +257,7 @@ def test_sparse_proj_backend_parity(backend):
     if backend == "local":
         h_cmp = h_ref
     else:
-        mesh = jax.make_mesh((jax.device_count(),), ("pod",))
+        mesh = make_mesh((jax.device_count(),), ("pod",))
         _, h_cmp = run_rounds(task, pipe, cohort,
                               RoundConfig(n_rounds=3, backend=backend,
                                           mesh=mesh))

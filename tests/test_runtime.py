@@ -189,6 +189,21 @@ def test_hier_validation():
                    RoundConfig(hierarchy="hier", pods=2, backend="gspmd"))
 
 
+def test_spawn_local_refuses_unless_the_cpu_was_asked_for(monkeypatch):
+    """On an accelerator host the forked workers would run the rounds on the
+    host CPU; spawn_local refuses before forking anything."""
+    from repro.runtime import spawn_local
+    from repro.runtime.workers import kv_roundtrip_worker
+
+    for value in (None, "tpu"):
+        if value is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", value)
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            spawn_local(kv_roundtrip_worker, 2)
+
+
 # ------------------------------------------ multi-process (slow, subprocess)
 #
 # spawn_local is exercised from a `python -c` child so the pytest process
