@@ -26,6 +26,7 @@ an exact in-graph dot product (again differentiable in rho).
 """
 from __future__ import annotations
 
+import fcntl
 import functools
 import os
 
@@ -111,11 +112,18 @@ def srht_eig_bank(
     """(trials, nk) eigenvalue bank for S with n random-map clients; disk-cached."""
     trials = trials or default_trials(n, k)
     path = _bank_path(n, k, d, trials, seed, projection)
-    if os.path.exists(path):
-        return np.load(path)["eigs"]
-    eigs = _simulate_bank(n, k, d, trials, seed, projection)
-    np.savez_compressed(path, eigs=eigs)
-    return eigs
+    if not os.path.exists(path):
+        # one simulation per bank across processes: the others wait on the
+        # lock and then load the bank the first one moved into place whole
+        with open(path + ".lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if not os.path.exists(path):
+                eigs = _simulate_bank(n, k, d, trials, seed, projection)
+                tmp = path + ".tmp.npz"
+                np.savez_compressed(tmp, eigs=eigs)
+                os.replace(tmp, path)
+                return eigs
+    return np.load(path)["eigs"]
 
 
 def sparse_eig_bank(

@@ -4,12 +4,12 @@ Each case runs in a fresh interpreter: JAX decides once per process, at its
 first compile, whether and where the cache is used."""
 import os
 import pathlib
-import subprocess
 import sys
 
 import pytest
 
 from repro.launch import compile_cache
+from support import run_child
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -45,8 +45,9 @@ def test_compiled_programs_land_in_the_cache_dir(tmp_path, env_set):
         argv.append(str(tmp_path / "not_used"))
     else:
         argv.append(str(want))
-    out = subprocess.run(argv, env=env, capture_output=True, text=True,
-                         timeout=300, check=True).stdout.split()
+    proc = run_child(argv, timeout=60, env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = proc.stdout.split()
     assert out[-2:] == [str(want), str(want)]
     assert any(name.endswith("-cache") for name in os.listdir(want))
     assert not (tmp_path / "not_used").exists()

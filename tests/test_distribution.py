@@ -1,8 +1,6 @@
 """Distribution layer: sharding rules, compressed-mean collective, and an
 in-subprocess 8-device mesh lower+compile (keeps the main test process on
 1 device as required)."""
-import os
-import subprocess
 import sys
 import textwrap
 
@@ -21,6 +19,7 @@ from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.optim import AdamW
 from repro.train import make_train_step
+from support import run_child, src_env
 
 
 class FakeMesh:
@@ -167,10 +166,6 @@ _SUBPROC = textwrap.dedent(
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["musicgen-medium", "deepseek-moe-16b"])
 def test_mesh_compile_in_subprocess(arch):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SUBPROC.format(arch=arch)],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
+    out = run_child([sys.executable, "-c", _SUBPROC.format(arch=arch)],
+                    timeout=60, env=src_env())
     assert "SUBPROC_OK" in out.stdout, out.stderr[-2000:]

@@ -10,6 +10,8 @@ Validates (against the paper's own claims):
   - App. A.1: same rotation for all clients gives no improvement
 """
 import functools
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +21,7 @@ import pytest
 from repro.core import chunking, codec, correlation, mean_estimate
 from repro.core import beta as beta_lib
 from repro.core.estimators import decode, encode_all
+from support import src_env
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -278,6 +281,47 @@ def test_rank_s_full_whp():
     bank = beta_lib.srht_eig_bank(n, k, d, trials=64, seed=1)
     ranks = (bank > 1e-4).sum(axis=1)
     assert (ranks == n * k).mean() > 0.95
+
+
+_BANK_CHILD = """
+import os, pathlib, sys, time
+import numpy as np
+from repro.core import beta
+root = pathlib.Path(os.environ["REPRO_BETA_CACHE"])
+simulate = beta._simulate_bank
+def counted(*args):
+    with open(root / "simulated.log", "a") as f:
+        f.write(sys.argv[1] + "\\n")
+    time.sleep(1.0)  # keep the bank missing while the other process asks
+    return simulate(*args)
+beta._simulate_bank = counted
+(root / f"ready{sys.argv[1]}").touch()
+while len(list(root.glob("ready*"))) < 2:
+    time.sleep(0.01)
+np.save(root / f"bank{sys.argv[1]}.npy", beta.srht_eig_bank(2, 4, 16))
+"""
+
+
+def test_eig_bank_is_simulated_once_across_processes(tmp_path):
+    """Two processes ask for the same new bank at once: one simulates it
+    under the bank's file lock, the other waits and loads the file, which
+    lands whole; both get the same array."""
+    env = src_env(REPRO_BETA_CACHE=str(tmp_path), JAX_PLATFORMS="cpu")
+    procs = [subprocess.Popen([sys.executable, "-c", _BANK_CHILD, str(i)],
+                              env=env, stderr=subprocess.PIPE, text=True)
+             for i in range(2)]
+    try:
+        errs = [p.communicate(timeout=60)[1] for p in procs]
+    finally:
+        for p in procs:
+            p.kill()
+    assert [p.returncode for p in procs] == [0, 0], errs
+    assert len((tmp_path / "simulated.log").read_text().split()) == 1
+    banks = [np.load(tmp_path / f"bank{i}.npy") for i in range(2)]
+    assert banks[0].shape == (beta_lib.default_trials(2, 4), 8)
+    np.testing.assert_array_equal(banks[0], banks[1])
+    assert [p.name for p in tmp_path.glob("*.npz")] == \
+        ["srht_eigs_n2_k4_d16_t512_s0.npz"]
 
 
 def test_chunking_roundtrip():

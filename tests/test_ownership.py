@@ -20,39 +20,22 @@ The tentpole claims pinned here:
 4. **Rejection** — cross-chunk decode statistics (`rand_k_spatial` with
    `r_mode="est"`) are rejected with the offending stage named, never
    silently mis-decoded.
+
+The plan, the owner-sliced decode core and the byte model are pinned here;
+tree-level ownership (the 8-device subprocess and the train step included)
+in `test_ownership_tree.py`, `fl.rounds` in `test_ownership_rounds.py`.
 """
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ownership_cases import ALL_ESTIMATORS, D, K, client_tree
 from repro.core import codec
 from repro.dist import collectives
 from repro.dist.sharding import ChunkOwnership, chunk_ownership
-from repro.fl import Cohort, RoundConfig, get_task, run_rounds
-from repro.launch.mesh import make_mesh
-
-D = 128
-K = 16
-
-
-def _tree(np_rng, n=6):
-    return {
-        "w": jnp.asarray(np_rng.standard_normal((n, 40, 20)), jnp.float32),
-        "b": jnp.asarray(np_rng.standard_normal((n, 33)), jnp.float32),
-    }
-
-
-def _assert_trees_equal(a, b):
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ------------------------------------------------------------------- the plan
@@ -97,25 +80,6 @@ def test_ownership_plan_validates():
 
 # --------------------------------------------------- owner-sliced decode core
 
-
-ALL_ESTIMATORS = [
-    codec.RandK(k=K, d_block=D),
-    codec.RandK(k=K, d_block=D, shared_randomness=False),
-    codec.RandKSpatial(k=K, d_block=D, transform="avg"),
-    codec.RandProjSpatial(k=K, d_block=D, transform="avg"),
-    codec.RandProjSpatial(k=K, d_block=D, transform="avg",
-                          shared_randomness=False),
-    codec.TopK(k=K, d_block=D),
-    codec.Wangni(k=K, d_block=D),
-    codec.Induced(k=K, d_block=D),
-    codec.Identity(d_block=D),
-    codec.SparseProj(k=K, d_block=D, transform="avg"),
-    codec.SparseProj(k=K, d_block=D, transform="avg",
-                     shared_randomness=False),
-    codec.Pipeline([codec.RandK(k=K, d_block=D), codec.Int8Quant()]),
-    codec.Pipeline([codec.RandProjSpatial(k=K, d_block=D), codec.Bf16Quant()]),
-    codec.Pipeline([codec.SparseProj(k=K, d_block=D), codec.Int8Quant()]),
-]
 
 # rand_proj_spatial's online R-hat is a PER-CHUNK statistic (shardable), but
 # its einsum contraction associates differently for different slice widths:
@@ -205,71 +169,6 @@ def test_sharded_decode_rejects_sparse_proj_pooled_rhat(rng_key, np_rng):
     # the gate is about the pooled statistic, not the sparsifier per se.
 
 
-# ------------------------------------------------------- tree-level ownership
-
-
-@pytest.mark.parametrize("spec", ALL_ESTIMATORS,
-                         ids=lambda s: codec.as_pipeline(s).describe())
-def test_tree_ownership_parity_gspmd(spec, rng_key, np_rng):
-    tree = _tree(np_rng)
-    pipe = codec.as_pipeline(spec)
-    m0, i0, _ = collectives.compressed_mean_tree(pipe, rng_key, tree)
-    m1, i1, _ = collectives.compressed_mean_tree(pipe, rng_key, tree,
-                                                 ownership=3)
-    _assert_trees_equal(m0, m1)
-    assert i1["n_shards"] == 3
-    assert i1["intra_pod_bytes"] == i1["intra_pod_bytes_ownership"]
-    assert i0["intra_pod_bytes"] == 0  # single logical shard, nothing crosses
-
-
-def test_tree_ownership_with_participants_and_ef(rng_key, np_rng):
-    tree = _tree(np_rng)
-    pipe = codec.Pipeline([codec.RandK(k=K, d_block=D), codec.ErrorFeedback()])
-    part = [0, 2, 5]
-    m0, _, e0 = collectives.compressed_mean_tree(
-        pipe, rng_key, tree, participants=part)
-    m1, _, e1 = collectives.compressed_mean_tree(
-        pipe, rng_key, tree, participants=part, ownership=4)
-    _assert_trees_equal(m0, m1)
-    np.testing.assert_array_equal(np.asarray(e0), np.asarray(e1))
-
-
-def test_tree_ownership_composes_with_overlap(rng_key, np_rng):
-    tree = _tree(np_rng)
-    pipe = codec.as_pipeline(codec.RandProjSpatial(k=K, d_block=D))
-    m0, _, _ = collectives.compressed_mean_tree(pipe, rng_key, tree)
-    for tile in (1, 2, 5):
-        m1, _, _ = collectives.compressed_mean_tree(
-            pipe, rng_key, tree, ownership=3, overlap=True, overlap_tile=tile)
-        _assert_trees_equal(m0, m1)
-
-
-def test_tree_ownership_plan_mismatch_raises(rng_key, np_rng):
-    tree = _tree(np_rng)
-    pipe = codec.as_pipeline(codec.RandK(k=K, d_block=D))
-    with pytest.raises(ValueError, match="covers"):
-        collectives.compressed_mean_tree(
-            pipe, rng_key, tree, ownership=chunk_ownership(3, 2))
-
-
-def test_shardmap_ownership_parity_one_device(rng_key, np_rng):
-    """The shard_map route (all_to_all + all_gather of means) on however many
-    local devices exist — the full multi-shard parity runs in the
-    subprocess test below."""
-    tree = _tree(np_rng)
-    mesh = jax.make_mesh((jax.device_count(),), ("pod",))
-    pipe = codec.as_pipeline(codec.RandProjSpatial(k=K, d_block=D))
-    m0, _, _ = collectives.compressed_mean_tree_shardmap(
-        pipe, rng_key, tree, mesh)
-    m1, i1, _ = collectives.compressed_mean_tree_shardmap(
-        pipe, rng_key, tree, mesh, ownership=True)
-    _assert_trees_equal(m0, m1)
-    m2, _, _ = collectives.compressed_mean_tree_shardmap(
-        pipe, rng_key, tree, mesh, ownership=True, overlap=True,
-        overlap_tile=2)
-    _assert_trees_equal(m0, m2)
-
-
 # ------------------------------------------------------- intra-pod byte model
 
 
@@ -309,7 +208,7 @@ def test_intra_pod_reduction_helper():
 
 
 def test_info_columns_present_on_both_entry_points(rng_key, np_rng):
-    tree = _tree(np_rng)
+    tree = client_tree(np_rng)
     pipe = codec.as_pipeline(codec.RandK(k=K, d_block=D))
     _, info, _ = collectives.compressed_mean_tree(pipe, rng_key, tree)
     for k in ("n_shards", "intra_pod_bytes", "intra_pod_bytes_allgather",
@@ -319,200 +218,3 @@ def test_info_columns_present_on_both_entry_points(rng_key, np_rng):
     _, info2, _ = collectives.compressed_mean_tree_shardmap(
         pipe, rng_key, tree, mesh, ownership=True)
     assert info2["n_shards"] == jax.device_count()
-
-
-# ------------------------------------------------------------------ fl rounds
-
-
-@pytest.mark.parametrize("backend", ["local", "gspmd", "shard_map"])
-def test_rounds_ownership_parity(backend):
-    """The fl acceptance: ownership decoding changes neither the MSE
-    trajectory nor the transmitted-byte ledger on any backend."""
-    task = get_task("drift", n_clients=8, d=D, rho=0.95, omega=0.02)
-    pipe = codec.RandProjSpatial(k=K, d_block=D, transform="avg")
-    cohort = Cohort(n_clients=8, dropout=0.2)
-    mesh = None if backend == "local" else make_mesh(
-        (jax.device_count(),), ("pod",))
-    base = dict(n_rounds=4, backend=backend, mesh=mesh)
-    _, h0 = run_rounds(task, pipe, cohort, RoundConfig(**base))
-    _, h1 = run_rounds(task, pipe, cohort,
-                       RoundConfig(**base, ownership=True, n_owners=4))
-    assert h0.mse == h1.mse
-    assert h0.bytes == h1.bytes
-    # the ownership run ledgers its modelled intra-pod traffic per round
-    assert len(h1.intra_pod_bytes) == 4
-    if backend == "local":
-        assert all(b > 0 for b in h1.intra_pod_bytes)
-        assert all(b == 0 for b in h0.intra_pod_bytes)
-
-
-def test_rounds_ownership_heterogeneous_budgets():
-    """Owners see mixed per-client k_i: budget groups decode independently
-    through the sharded path, and the trajectory matches the unsharded one."""
-    budgets = (8, 8, 8, 32, 32, 32, 16, 16)
-    task = get_task("drift", n_clients=8, d=D, rho=0.95, omega=0.02)
-    pipe = codec.RandK(k=K, d_block=D)
-    cohort = Cohort(n_clients=8, dropout=0.2, budgets=budgets)
-    _, h0 = run_rounds(task, pipe, cohort, RoundConfig(n_rounds=4))
-    _, h1 = run_rounds(task, pipe, cohort,
-                       RoundConfig(n_rounds=4, ownership=True, n_owners=2))
-    assert h0.mse == h1.mse
-    assert h0.bytes == h1.bytes
-
-
-def test_rounds_ownership_composes_with_overlap_and_async():
-    task = get_task("drift", n_clients=8, d=D, rho=0.95, omega=0.02)
-    pipe = codec.RandProjSpatial(k=K, d_block=D, transform="avg")
-    cohort = Cohort(n_clients=8, dropout=0.3)
-    base = dict(n_rounds=5)
-    _, h0 = run_rounds(task, pipe, cohort, RoundConfig(**base))
-    _, h1 = run_rounds(task, pipe, cohort, RoundConfig(
-        **base, ownership=True, n_owners=3, overlap=True, overlap_tile=2))
-    assert h0.mse == h1.mse
-    _, h2 = run_rounds(task, pipe, cohort, RoundConfig(**base,
-                                                       async_rounds=True))
-    _, h3 = run_rounds(task, pipe, cohort, RoundConfig(
-        **base, async_rounds=True, ownership=True, n_owners=3))
-    assert h2.mse == h3.mse and h2.bytes == h3.bytes
-    assert sum(h3.n_stale) == sum(h2.n_stale)
-
-
-def test_rounds_ownership_composes_with_ef_and_temporal():
-    task = get_task("drift", n_clients=6, d=D, rho=0.95, omega=0.02,
-                    client_bias=0.5)
-    cohort = Cohort(n_clients=6, dropout=0.2)
-    for stages in ([codec.RandK(k=K, d_block=D), codec.ErrorFeedback()],
-                   [codec.RandK(k=K, d_block=D), codec.Temporal()]):
-        pipe = codec.Pipeline(stages)
-        _, h0 = run_rounds(task, pipe, cohort, RoundConfig(n_rounds=4))
-        _, h1 = run_rounds(task, pipe, cohort,
-                           RoundConfig(n_rounds=4, ownership=True, n_owners=3))
-        assert h0.mse == h1.mse
-
-
-def test_rounds_ownership_rejects_cross_chunk_decode():
-    task = get_task("dme", n_clients=4, d=D, rho=0.9)
-    pipe = codec.RandKSpatial(k=K, d_block=D, transform="avg", r_mode="est")
-    with pytest.raises(ValueError, match="RandKSpatial"):
-        run_rounds(task, pipe, cfg=RoundConfig(n_rounds=1, ownership=True,
-                                               n_owners=2))
-
-
-# ------------------------------------------------------------------ train step
-
-
-def test_train_step_ownership_parity():
-    from repro import configs
-    from repro.data import SyntheticLM
-    from repro.models import init_params
-    from repro.optim import AdamW
-    from repro.train import make_train_step
-
-    cfg = configs.reduce_for_smoke(configs.get_config("musicgen-medium"))
-    opt = AdamW(lr=1e-2, warmup_steps=1)
-    params = init_params(cfg, jax.random.key(0))
-    data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=16, batch=3,
-                       n_clients=2)
-    batch = data.batch_at(0)
-    spec = codec.build("rand_k", k=64, d_block=512)
-    s0 = jax.jit(make_train_step(cfg, opt, dme_spec=spec))
-    s1 = jax.jit(make_train_step(cfg, opt, dme_spec=spec, dme_ownership=4))
-    p0, _, m0 = s0(params, {"opt": opt.init(params)}, batch, 0)
-    p1, _, m1 = s1(params, {"opt": opt.init(params)}, batch, 0)
-    for a, b in zip(jax.tree.leaves(p0), jax.tree.leaves(p1)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert m1["intra_pod_reduction"] > 0
-
-
-# ---------------------------------------------- real multi-shard routing (slow)
-
-
-_SUBPROC = textwrap.dedent(
-    """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax, jax.numpy as jnp
-    import numpy as np
-    from repro.core import codec
-    from repro.dist import collectives
-
-    key = jax.random.key(0)
-    rng = np.random.default_rng(0)
-    tree = {"w": jnp.asarray(rng.standard_normal((8, 40, 20)), jnp.float32),
-            "b": jnp.asarray(rng.standard_normal((8, 33)), jnp.float32)}
-    mesh = jax.make_mesh((4,), ("pod",))
-
-    specs = [
-        codec.RandProjSpatial(k=16, d_block=128),
-        codec.RandK(k=16, d_block=128, shared_randomness=False),
-        codec.Wangni(k=16, d_block=128),
-        codec.Induced(k=16, d_block=128),
-        codec.Identity(d_block=128),
-        codec.Pipeline([codec.RandK(k=16, d_block=128), codec.Int8Quant()]),
-    ]
-    for spec in specs:
-        pipe = codec.as_pipeline(spec)
-        # warm any beta eigenvalue bank OUTSIDE the mesh trace: the
-        # host-side bank simulation cannot run inside shard_map
-        collectives.compressed_mean_tree(pipe, key, tree)
-        m0, i0, _ = collectives.compressed_mean_tree_shardmap(
-            pipe, key, tree, mesh)
-        m1, i1, _ = collectives.compressed_mean_tree_shardmap(
-            pipe, key, tree, mesh, ownership=True)
-        for a, b in zip(jax.tree.leaves(m0), jax.tree.leaves(m1)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert i1["n_shards"] == 4
-
-    # participants + EF + overlap through the real all_to_all routing
-    pipe_ef = codec.Pipeline([codec.RandK(k=16, d_block=128),
-                              codec.ErrorFeedback()])
-    m2, _, e2 = collectives.compressed_mean_tree_shardmap(
-        pipe_ef, key, tree, mesh, participants=[0, 2, 5, 6, 7])
-    m3, _, e3 = collectives.compressed_mean_tree_shardmap(
-        pipe_ef, key, tree, mesh, participants=[0, 2, 5, 6, 7],
-        ownership=True)
-    for a, b in zip(jax.tree.leaves(m2), jax.tree.leaves(m3)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(e2), np.asarray(e3))
-    m4, _, e4 = collectives.compressed_mean_tree_shardmap(
-        pipe_ef, key, tree, mesh, ownership=True, overlap=True,
-        overlap_tile=2)
-    m5, _, e5 = collectives.compressed_mean_tree_shardmap(
-        pipe_ef, key, tree, mesh)
-    for a, b in zip(jax.tree.leaves(m4), jax.tree.leaves(m5)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(e4), np.asarray(e5))
-
-    # the reduction regime, measured off the real route's info dict: n*k
-    # payload bytes per chunk >> d bytes per chunk (warm the beta bank
-    # OUTSIDE the mesh trace; host-side simulation cannot run inside it)
-    big = {"w": jnp.asarray(rng.standard_normal((8, 64, 32)), jnp.float32)}
-    pipe_big = codec.as_pipeline(
-        codec.RandProjSpatial(k=64, d_block=128, beta_trials=8))
-    collectives.compressed_mean_tree(pipe_big, key, big)
-    mb0, ib0, _ = collectives.compressed_mean_tree_shardmap(
-        pipe_big, key, big, mesh)
-    mb1, ib1, _ = collectives.compressed_mean_tree_shardmap(
-        pipe_big, key, big, mesh, ownership=True)
-    for a, b in zip(jax.tree.leaves(mb0), jax.tree.leaves(mb1)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
-    assert ib1["intra_pod_bytes_ownership"] < ib1["intra_pod_bytes_allgather"]
-    print("SUBPROC_OK")
-    """
-)
-
-
-@pytest.mark.slow
-def test_shardmap_ownership_multi_shard_in_subprocess():
-    """4 real shards: all_to_all payload routing + all_gather of decoded
-    means is bit-identical to the replicated all-gather decode for every
-    estimator family, and the intra-pod ledger reduction holds."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SUBPROC],
-        capture_output=True, text=True, timeout=600, env=env,
-    )
-    assert "SUBPROC_OK" in out.stdout, out.stderr[-2000:]

@@ -1,10 +1,10 @@
 """Pipeline parallelism vs serial reference (4 host devices, subprocess)."""
-import os
-import subprocess
 import sys
 import textwrap
 
 import pytest
+
+from support import run_child, src_env
 
 _SUBPROC = textwrap.dedent(
     """
@@ -46,12 +46,8 @@ _SUBPROC = textwrap.dedent(
 
 @pytest.mark.slow
 def test_pipeline_matches_serial():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SUBPROC], capture_output=True, text=True,
-        timeout=600, env=env,
-    )
+    out = run_child([sys.executable, "-c", _SUBPROC], timeout=60,
+                    env=src_env())
     assert "PIPELINE_OK" in out.stdout, out.stderr[-2000:]
 
 
@@ -196,10 +192,6 @@ _SUBPROC_STEP = textwrap.dedent(
 @pytest.mark.slow
 def test_pipelined_train_step_two_stages():
     """Real 2-stage GPipe on 2 host devices vs the unpipelined step."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _SUBPROC_STEP], capture_output=True, text=True,
-        timeout=600, env=env,
-    )
+    out = run_child([sys.executable, "-c", _SUBPROC_STEP], timeout=150,
+                    env=src_env())
     assert "PIPELINE_STEP_OK" in out.stdout, out.stderr[-2000:]

@@ -7,8 +7,6 @@ bitwise identical to the single-process run at any pod count (every process
 decodes its owned pods and learns the rest via the KV exchange, so all
 processes hold the same History).
 """
-import os
-import subprocess
 import sys
 import textwrap
 
@@ -26,6 +24,7 @@ from repro.runtime import (
     free_port,
 )
 from repro.runtime.workers import history_arrays
+from support import run_child, src_env
 
 D = 64
 
@@ -312,13 +311,8 @@ _SUBPROC_VARIANTS = _COMMON + textwrap.dedent(
 )
 
 
-def _run_subproc(script):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    return subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=600, env=env,
-    )
+def _run_subproc(script, timeout):
+    return run_child([sys.executable, "-c", script], timeout, env=src_env())
 
 
 _SUBPROC_PSUM = textwrap.dedent(
@@ -349,19 +343,19 @@ _SUBPROC_PSUM = textwrap.dedent(
 def test_psum_scatter_mean_on_real_mesh():
     """Pre-placed payload tiles reduce to the weighted mean on a 4-device
     mesh, including ragged chunk counts (padded psum_scatter splits)."""
-    out = _run_subproc(_SUBPROC_PSUM)
+    out = _run_subproc(_SUBPROC_PSUM, timeout=60)
     assert "PSUM_SCATTER_OK" in out.stdout, out.stderr[-2000:]
 
 
 @pytest.mark.slow
 def test_two_process_hier_matches_single_process():
-    out = _run_subproc(_SUBPROC_PARITY)
+    out = _run_subproc(_SUBPROC_PARITY, timeout=270)
     assert "RUNTIME_PARITY_OK" in out.stdout, \
         out.stdout[-1000:] + out.stderr[-2000:]
 
 
 @pytest.mark.slow
 def test_two_process_hier_variants_match_single_process():
-    out = _run_subproc(_SUBPROC_VARIANTS)
+    out = _run_subproc(_SUBPROC_VARIANTS, timeout=300)
     assert "RUNTIME_VARIANTS_OK" in out.stdout, \
         out.stdout[-1000:] + out.stderr[-2000:]
