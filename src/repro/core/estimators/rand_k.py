@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...kernels import ops as kops
 from . import base
 
 
@@ -89,7 +90,7 @@ def encode(spec, key, client_id, x_cd):
             [x_cd[ci, idxs[ci]] for ci in range(c)]
         )}
     idx = _indices(spec, key, client_id, c)
-    vals = jnp.take_along_axis(x_cd, idx, axis=-1)
+    vals = kops.select_coords(x_cd, idx, use_pallas=getattr(spec, "use_pallas", "auto"))
     return {"vals": vals}
 
 
@@ -100,15 +101,18 @@ def scatter_sum_and_counts(spec, key, vals, n, client_ids=None, chunk_offset=0):
     ``client_ids`` overrides the 0..n-1 id assignment (partial participation);
     ``chunk_offset`` is the global position of vals' first chunk (owner-sliced
     decode) — the scatter itself is per-chunk, so rows are independent.
+    A client's indices are distinct within a chunk, so its scatter is a
+    row-wise ``expand_coords``; the sum over clients adds them.
     """
     c = vals.shape[1]
     d = spec.d_block
     ids = jnp.arange(n) if client_ids is None else jnp.asarray(client_ids)
+    use_pallas = getattr(spec, "use_pallas", "auto")
 
     def one(client_id, v):
         idx = _indices(spec, key, client_id, c, chunk_offset)
-        s = jnp.zeros((c, d), v.dtype).at[jnp.arange(c)[:, None], idx].add(v)
-        m = jnp.zeros((c, d), jnp.float32).at[jnp.arange(c)[:, None], idx].add(1.0)
+        s = kops.expand_coords(v, idx, d, use_pallas=use_pallas)
+        m = kops.expand_coords(1.0, idx, d, use_pallas=use_pallas)
         return s, m
 
     ss, ms = jax.vmap(one)(ids, vals)

@@ -113,7 +113,7 @@ def encode(spec, key, client_id, x_cd):
         draws = jax.vmap(lambda kk: _client_draw(spec, kk))(keys)
         if "signs" in draws:
             # fused batched encode: per-chunk sign flip + FWHT in one pass
-            # (kernels/srht_fused.py); the row gather stays in XLA.
+            # (kernels/srht_fused.py), then the row-wise select.
             vals = kops.srht_encode_batch(
                 x_cd, draws["signs"], draws["rows"], use_pallas=spec.use_pallas
             )
@@ -330,7 +330,7 @@ def _decode_fused(spec, key, payloads, n, client_ids, chunk_offset):
     else:
         rho = jnp.asarray(transforms.rho_for(spec.transform, n, spec.r_value))
 
-    mask = kref.srht_scatter_ref(jnp.ones(rows.shape, jnp.float32), rows, d)
+    mask = kops.expand_coords(1.0, rows, d, use_pallas=spec.use_pallas)  # (n, Cs, d)
 
     if spec.projection == "subsample":
         # S = diag(hit counts): (T(S))^dagger is a closed-form elementwise

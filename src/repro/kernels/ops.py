@@ -10,6 +10,12 @@ Fused batched ops behind the rand_proj_spatial fast path (docs/KERNELS.md):
 ``srht_decode_sum``   - y_c = sum_i G_i^T z_ic in one launch.
 ``srht_gram_apply``   - matrix-free S v = sum_i G_i^T G_i v (CG inner apply).
 
+Row-wise coordinate selection, shared by Rand-Proj-Spatial and Rand-k
+(kernels/select.py):
+
+``select_coords`` - out[..r.., j] = t[..r.., rows[..r.., j]] (the encode's gather).
+``expand_coords`` - its adjoint: the k values back at full width, zero elsewhere.
+
 On TPU the Pallas kernel is used (compiled); elsewhere the same kernel body
 runs in interpret mode, or the pure-jnp oracle for tiny shapes where the
 interpreter overhead dominates. The oracle (kernels/ref.py) is the
@@ -37,24 +43,36 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _should_use_pallas(n_elems: int, use_pallas: str | bool) -> tuple[bool, bool]:
-    """-> (use_kernel, interpret)"""
+def _should_use_pallas(
+    size: int, use_pallas: str | bool, tile: int | None = None
+) -> tuple[bool, bool]:
+    """-> (use_kernel, interpret).
+
+    ``size`` counts elements; with ``tile`` it counts rows, and ``tile`` is
+    the rows of one full kernel tile. That is the row-wise select/expand
+    pair: on TPU it takes the kernel from one full tile of rows, and XLA's
+    gather/scatter below it (where a grid step's overhead buys nothing) and
+    off the TPU."""
     if use_pallas == "force":
         return True, not _on_tpu()
     if use_pallas == "never" or use_pallas is False:
         return False, False
+    if tile is not None:
+        return _on_tpu() and size >= tile, False
     if _on_tpu():
         return True, False
-    return n_elems >= _PALLAS_MIN_ELEMS, True
+    return size >= _PALLAS_MIN_ELEMS, True
 
 
-def _dispatch(op: str, n_elems: int, use_pallas: str | bool) -> tuple[bool, bool]:
+def _dispatch(
+    op: str, size: int, use_pallas: str | bool, tile: int | None = None
+) -> tuple[bool, bool]:
     """``_should_use_pallas`` + one telemetry count per decision.
 
     The decision is a Python static, so under jit it records at trace time —
     i.e. once per compilation, which is exactly the granularity at which the
     route is actually chosen."""
-    use, interp = _should_use_pallas(n_elems, use_pallas)
+    use, interp = _should_use_pallas(size, use_pallas, tile)
     _record_dispatch(op, use, interp)
     return use, interp
 
@@ -174,8 +192,7 @@ def srht_encode_batch(
         t = fwht_rowsigns_pallas(x2, s2, sign_pre=True, scale=inv, interpret=interp)
     else:
         t = _ref.fwht_rowsigns_ref(x2, s2, sign_pre=True, scale=inv)
-    t = t.reshape(*lead, d)
-    return jnp.take_along_axis(t, rows, axis=-1)
+    return select_coords(t.reshape(*lead, d), rows, use_pallas=use_pallas)
 
 
 def srht_decode_sum(
@@ -191,13 +208,13 @@ def srht_decode_sum(
     z: (n, C, k); signs: (n, C|1, d); rows: (n, C|1, k) — the middle axis is 1
     when clients share one draw across chunks (shared_randomness). -> (C, d)
 
-    The scatter to full width stays in XLA (cheap, k << d, fuses with the
-    payload unpack); the FWHT + sign/scale + scatter-add over clients is one
-    Pallas launch batched over (clients x chunks).
+    The scatter to full width is ``expand_coords``; the FWHT + sign/scale +
+    scatter-add over clients is one Pallas launch batched over
+    (clients x chunks).
     """
     from .srht_fused import srht_decode_sum_pallas
 
-    full = _ref.srht_scatter_ref(z, rows, d)  # (n, C, d)
+    full = expand_coords(z, rows, d, use_pallas=use_pallas)  # (n, C, d)
     use, interp = _dispatch("srht_decode_sum", full.size, use_pallas)
     inv = 1.0 / math.sqrt(d)
     if use:
@@ -227,6 +244,67 @@ def srht_gram_apply(
     if use:
         return srht_gram_apply_pallas(v, signs, mask, scale=1.0 / d, interpret=interp)
     return _ref.srht_gram_apply_ref(v, signs, mask)
+
+
+def _row_kernel_route(dtype, use_pallas: str | bool) -> str | bool:
+    """The select/expand kernels move 4-byte values; other dtypes stay on XLA."""
+    return use_pallas if jnp.dtype(dtype).itemsize == 4 else "never"
+
+
+def select_coords(
+    t: jnp.ndarray, rows: jnp.ndarray, *, use_pallas: str | bool = "auto"
+) -> jnp.ndarray:
+    """Row-wise gather ``jnp.take_along_axis(t, rows, -1)``, bit for bit.
+
+    t: (..., d); rows: (..., k) int32 in [0, d), leading dims broadcastable
+    to t's. -> (..., k). Works under vmap (the per-client encode)."""
+    from .select import select_coords_pallas, tile_rows
+
+    d, k = t.shape[-1], rows.shape[-1]
+    lead = t.shape[:-1]
+    rows = jnp.broadcast_to(rows, lead + (k,))
+    use, interp = _dispatch(
+        "select_coords", math.prod(lead), _row_kernel_route(t.dtype, use_pallas),
+        tile=tile_rows(d),
+    )
+    if not use:
+        return jnp.take_along_axis(t, rows, axis=-1)
+    out = select_coords_pallas(t.reshape(-1, d), rows.reshape(-1, k), interpret=interp)
+    return out.reshape(*lead, k)
+
+
+def expand_coords(
+    z: jnp.ndarray | float, rows: jnp.ndarray, d: int, *,
+    use_pallas: str | bool = "auto",
+) -> jnp.ndarray:
+    """Row-wise scatter ``zeros(d).at[rows].set(z)`` per row, bit for bit.
+
+    z: (..., k), or a Python float that every selected coordinate takes (the
+    0/1 mask, hit counts: no (..., k) array of it is made); rows: (..., k)
+    int32, DISTINCT within a row (every caller's draw is without
+    replacement), leading dims broadcastable to z's. -> (..., d), float32
+    for a float z."""
+    from .select import expand_coords_pallas, tile_rows
+
+    fill = None
+    if isinstance(z, float):
+        fill, z = float(z), None
+    lead = rows.shape[:-1] if z is None else z.shape[:-1]
+    k = rows.shape[-1]
+    rows = jnp.broadcast_to(rows, lead + (k,))
+    dtype = jnp.float32 if z is None else z.dtype
+    use, interp = _dispatch(
+        "expand_coords", math.prod(lead), _row_kernel_route(dtype, use_pallas),
+        tile=tile_rows(d),
+    )
+    if not use:
+        vals = jnp.full(rows.shape, fill, dtype) if z is None else z
+        return _ref.srht_scatter_ref(vals, rows, d)
+    out = expand_coords_pallas(
+        None if z is None else z.reshape(-1, k), rows.reshape(-1, k), d,
+        fill=fill, interpret=interp,
+    )
+    return out.reshape(*lead, d)
 
 
 # ------------------------------------------------- very-sparse projection ops

@@ -162,6 +162,7 @@ def test_pick_block_rows_bounds():
     "fwht.fwht_pallas", "srht_fused.fwht_rowsigns_pallas",
     "srht_fused.srht_decode_sum_pallas", "srht_fused.srht_gram_apply_pallas",
     "flash_attention.flash_attention_pallas",
+    "select.select_coords_pallas", "select.expand_coords_pallas",
 ])
 def test_kernel_wrappers_default_to_compiled(name):
     """A caller that leaves ``interpret`` out gets the compiled kernel; the

@@ -1,4 +1,5 @@
-"""Compiles of the fused SRHT kernels for a described TPU v5e chip.
+"""Compiles of the fused SRHT kernels and the select/expand pair for a
+described TPU v5e chip.
 
 Nothing runs: the TPU compiler, installed with JAX, compiles for a chip that
 is described and not attached, and raises what the chip's compiler would
@@ -6,8 +7,10 @@ raise (a Mosaic kernel that asks for more VMEM than the core's scoped limit,
 a block not aligned to the tiling). Interpret mode sees none of that, so
 these compiles guard the kernels' tile sizing at the widths the DME training
 step really uses: a mamba2-130m gradient (129.1M parameters) chunked at
-d_block = 1024 is C = 126,075 chunks, exchanged by n = 4 clients. One small
-ragged shape covers padding of the chunk axis to the tile height.
+d_block = 1024 is C = 126,075 chunks, exchanged by n = 4 clients, each
+sending k = 64 coordinates per chunk. One small ragged shape covers padding
+of the chunk axis to the tile height, and the select/expand pair's partial
+last block.
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu at a time, and every test worker imports this
@@ -19,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fwht import fwht_pallas
+from repro.kernels.select import expand_coords_pallas, select_coords_pallas
 from repro.kernels.srht_fused import (
     fwht_rowsigns_pallas,
     srht_decode_sum_pallas,
@@ -58,6 +62,11 @@ def one_chip(topo):
 def _cases(n, c, d, spec):
     """name -> (kernel closure, argument shapes) for every fused kernel."""
     s = 1.0 / d**0.5
+    k = min(64, d // 4)  # the DME step's k = 64 at d = 1024
+
+    def idx(*dims):
+        return spec(*dims, dtype=jnp.int32)
+
     return {
         "fwht": (lambda x: fwht_pallas(x, interpret=False), (spec(c, d),)),
         "fwht_signs": (
@@ -88,10 +97,26 @@ def _cases(n, c, d, spec):
                                                   interpret=False),
             (spec(c, d), spec(n, 1, d), spec(n, 1, d)),
         ),
+        "select": (
+            lambda t, r: select_coords_pallas(t, r, interpret=False),
+            (spec(n * c, d), idx(n * c, k)),
+        ),
+        "select_per_client": (  # under the per-client vmap of the Rand-k encode
+            jax.vmap(lambda t, r: select_coords_pallas(t, r, interpret=False)),
+            (spec(n, c, d), idx(n, c, k)),
+        ),
+        "expand": (
+            lambda z, r: expand_coords_pallas(z, r, d, interpret=False),
+            (spec(n * c, k), idx(n * c, k)),
+        ),
+        "expand_fill": (
+            lambda r: expand_coords_pallas(None, r, d, fill=1.0, interpret=False),
+            (idx(n * c, k),),
+        ),
     }
 
 
-KERNELS = tuple(_cases(1, 1, 1, lambda *dims: dims))
+KERNELS = tuple(_cases(1, 1, 4, lambda *dims, dtype=None: dims))
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
@@ -99,8 +124,8 @@ KERNELS = tuple(_cases(1, 1, 1, lambda *dims: dims))
 def test_kernel_compiles_for_v5e(one_chip, shape, kernel):
     n, c, d = SHAPES[shape]
 
-    def spec(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    def spec(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     fn, args = _cases(n, c, d, spec)[kernel]
     compiled = jax.jit(fn).lower(*args).compile()
